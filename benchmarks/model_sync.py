@@ -31,11 +31,11 @@ def run():
         emit(f"fig12_multi_{name}_speedup", ring / hier16,
              "paper: 2.62-2.75x (our ring baseline is conservative)")
 
-    # real collective structure, via a 16-device subprocess
+    # real collective structure, lowered on 16 virtual CPU devices in a
+    # child process; the child pins itself to the CPU so it never contends
+    # for an accelerator this process may hold
     code = r"""
-import os
-os.environ['XLA_FLAGS']='--xla_force_host_platform_device_count=16'
-import json, re, sys
+import json, sys
 sys.path.insert(0, 'src')
 from repro.sync import lower_sync
 from repro.launch.hlo_cost import analyze_hlo
@@ -46,18 +46,20 @@ for mode in ('hierarchical','flat'):
     out[mode] = {k: v for k, v in c.coll.items()}
 print(json.dumps(out))
 """
-    try:
-        res = subprocess.run([sys.executable, "-c", code], cwd=os.getcwd(),
-                             capture_output=True, text=True, timeout=600)
-        data = json.loads(res.stdout.strip().splitlines()[-1])
-        hier_slow = data["hierarchical"]["collective-permute"]
-        flat_slow = data["flat"]["all-gather"]
-        emit("fig12_hlo_slowlink_bytes_hier", hier_slow,
-             "ppermute bytes crossing the cluster axis (one copy)")
-        emit("fig12_hlo_alllink_bytes_flat", flat_slow,
-             "flat all-gather bytes spanning both pools")
-    except Exception as e:  # pragma: no cover
-        emit("fig12_hlo_collectives", -1, f"subprocess failed: {e}")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=16")
+    res = subprocess.run([sys.executable, "-c", code], cwd=os.getcwd(),
+                         env=env, capture_output=True, text=True,
+                         timeout=600)
+    if res.returncode != 0:
+        raise RuntimeError(f"collective lowering child failed "
+                           f"(rc {res.returncode}):\n{res.stderr[-4000:]}")
+    data = json.loads(res.stdout.strip().splitlines()[-1])
+    emit("fig12_hlo_slowlink_bytes_hier",
+         data["hierarchical"]["collective-permute"],
+         "ppermute bytes crossing the cluster axis (one copy)")
+    emit("fig12_hlo_alllink_bytes_flat", data["flat"]["all-gather"],
+         "flat all-gather bytes spanning both pools")
 
 
 if __name__ == "__main__":

@@ -13,6 +13,7 @@ import time
 from benchmarks import (at_scale, decision_latency, interference, longtail,
                         model_sync, mux_micro, scheduler_quality, sensitivity,
                         warm_start)
+from repro.launch.compile_cache import enable_compile_cache
 
 SUITES = {
     "fig10_mux_micro": mux_micro.run,
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="shrink trace sizes for CI-speed runs")
     args = ap.parse_args()
+    enable_compile_cache()
     picked = {k.strip() for k in args.only.split(",") if k.strip()}
     print("name,value,derived")
     for name, fn in SUITES.items():

@@ -68,6 +68,7 @@ import numpy as np
 
 from repro.core.distributions import sample_response_fractions
 from repro.data import tokenizer as tok
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.rl import SamplerConfig, generate
 from repro.serve import (DisaggConfig, DisaggRouter, ElasticConfig,
@@ -871,6 +872,7 @@ def main():
                     help="report path ('' disables; default "
                          "BENCH_serve[_quick].json at the repo root)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         # the CI bench guard diffs this report's speedup ratios at 15%
         # tolerance, so the quick trace stays large enough (and best-of-5)

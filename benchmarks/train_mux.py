@@ -57,6 +57,7 @@ import numpy as np
 
 from repro.core.simulator import simulate_profiles
 from repro.data import tokenizer as tok
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.rl.agentic import CountdownToolEnv, run_episodes
 from repro.rl.coexec import (GRPOJob, run_coexec, run_pipelined,
@@ -210,6 +211,7 @@ def main():
                     help="report path ('' disables; default "
                          "BENCH_train_mux[_quick].json at the repo root)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.quick:
         # batch 4 = four GRPO groups per iteration: enough sub-phase
         # granularity for the streaming scenarios to show their overlap

@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 NEG_INF = -1.0e30
 # matches models/stacks.NO_WINDOW: far beyond any max_seq_len, never masks
 NO_WINDOW = 2 ** 30
@@ -128,7 +126,7 @@ def decode_attention(q, k, v, length, *, window=None, block_k: int = 512,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, win, qt, kt, vt)
@@ -152,12 +150,13 @@ def _paged_dec_kernel(tbl_ref, len_ref, win_ref, q_ref, k_ref, v_ref, *rest,
     q = q_ref[0, 0].astype(jnp.float32)               # (G, D)
     k = k_ref[0, 0].astype(jnp.float32)               # (bs, D)
     v = v_ref[0, 0].astype(jnp.float32)
-    if quant:
-        # per-position scales: dequantize this physical block in VMEM
-        k = k * ks_ref[0][:, None]
-        v = v * vs_ref[0][:, None]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
+    if quant:
+        # per-position scales ride the lane axis as (1, bs) rows: scaling
+        # score column j equals dequantizing K row j, and needs no
+        # sublane<->lane relayout of the scale vector
+        s = s * ks_ref[0]
     # logical position of this table entry's tokens; masks the live prefix,
     # the sliding window, and any null-block (table id 0) tail entries
     pos = ki * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -173,8 +172,10 @@ def _paged_dec_kernel(tbl_ref, len_ref, win_ref, q_ref, k_ref, v_ref, *rest,
     p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
     corr = jnp.exp(m_prev - m_new)
     l_s[...] = l_s[...] * corr + p.sum(axis=1)
+    # V's per-position scale weights the probabilities instead of V's rows
+    pv = p * vs_ref[0] if quant else p
     acc_s[...] = acc_s[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        pv, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_s[...] = m_new
 
     @pl.when(ki == nb - 1)
@@ -229,11 +230,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     ]
     operands = [qt, kt, vt]
     if quant:
+        # (NB, 1, bs): the block's last two dims are the array's own, which
+        # the TPU tiling rule accepts where a (1, bs) block of (NB, bs) is not
         scale_spec = pl.BlockSpec(
-            (1, bs), lambda b, h, ki, tbl, lens, w: (tbl[b, ki], 0))
+            (1, 1, bs), lambda b, h, ki, tbl, lens, w: (tbl[b, ki], 0, 0))
         in_specs += [scale_spec, scale_spec]
-        operands += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+        operands += [jnp.asarray(k_scale, jnp.float32).reshape(NB, 1, bs),
+                     jnp.asarray(v_scale, jnp.float32).reshape(NB, 1, bs)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,               # block tables, lengths, window
         grid=(B, Hkv, MB),
@@ -250,7 +253,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tbl, lengths, win, *operands)

@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 NEG_INF = -1.0e30
 
 
@@ -103,7 +101,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

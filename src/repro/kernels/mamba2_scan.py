@@ -14,8 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 
 def _ssd_kernel(r_ref, k_ref, v_ref, lw_ref, y_ref, st_ref, state_s, *,
                 chunk: int, n_chunks: int):
@@ -95,7 +93,7 @@ def mamba2_scan(r, k, v, log_w, *, chunk: int = 64, interpret: bool = True):
             jax.ShapeDtypeStruct((B, H, N, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, hd), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(rt, kt, vt, lwt)

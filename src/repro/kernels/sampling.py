@@ -26,14 +26,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import compiler_params
-
 NEG_INF = -1.0e30
 
 
 def _greedy_kernel(x_ref, tok_ref, lp_ref, m_s, l_s, idx_s, *,
                    bv: int, nv: int):
-    vi = pl.program_id(1)
+    vi = pl.program_id(0)
 
     @pl.when(vi == 0)
     def _init():
@@ -41,16 +39,18 @@ def _greedy_kernel(x_ref, tok_ref, lp_ref, m_s, l_s, idx_s, *,
         l_s[...] = jnp.zeros_like(l_s)
         idx_s[...] = jnp.zeros_like(idx_s)
 
-    x = x_ref[...].astype(jnp.float32)                # (1, bv)
-    bm = x.max(axis=1)                                # (1,)
-    bi = jnp.argmax(x, axis=1).astype(jnp.int32)      # (1,)
+    x = x_ref[...].astype(jnp.float32)                # (B, bv)
+    bm = x.max(axis=1, keepdims=True)                 # (B, 1)
+    # first in-block occurrence of the max, as a min over matching lanes
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    bi = jnp.min(jnp.where(x == bm, lane, bv), axis=1, keepdims=True)
     m_prev = m_s[...]
     m_new = jnp.maximum(m_prev, bm)
     # strict > keeps the first occurrence across blocks, matching
-    # jnp.argmax over the full row (within a block argmax already does)
+    # jnp.argmax over the full row
     idx_s[...] = jnp.where(bm > m_prev, vi * bv + bi, idx_s[...])
     l_s[...] = (l_s[...] * jnp.exp(m_prev - m_new)
-                + jnp.exp(x - m_new[:, None]).sum(axis=1))
+                + jnp.exp(x - m_new).sum(axis=1, keepdims=True))
     m_s[...] = m_new
 
     @pl.when(vi == nv - 1)
@@ -65,7 +65,9 @@ def greedy_sample(logits, *, block_v: int = 1024, interpret: bool = True):
     """logits: (B, V) -> (tokens (B,) int32, logprobs (B,) float32).
 
     One fused pass: ``tokens = argmax(logits)`` (first occurrence on ties)
-    and ``logprobs = log_softmax(logits)[tokens]``."""
+    and ``logprobs = log_softmax(logits)[tokens]``.  Every block holds all
+    B rows, so the block's row dim is the array's own whatever B is (the
+    TPU tiling rule), and the grid walks the vocabulary only."""
     B, V = logits.shape
     bv = min(block_v, V)
     nv = -(-V // bv)
@@ -75,24 +77,24 @@ def greedy_sample(logits, *, block_v: int = 1024, interpret: bool = True):
         x = jnp.pad(x, ((0, 0), (0, pad)), constant_values=NEG_INF)
 
     kernel = functools.partial(_greedy_kernel, bv=bv, nv=nv)
-    out = pl.pallas_call(
+    col = pl.BlockSpec((B, 1), lambda vi: (0, 0))
+    tokens, logprobs = pl.pallas_call(
         kernel,
-        grid=(B, nv),
-        in_specs=[pl.BlockSpec((1, bv), lambda b, vi: (b, vi))],
-        out_specs=(pl.BlockSpec((1,), lambda b, vi: (b,)),
-                   pl.BlockSpec((1,), lambda b, vi: (b,))),
-        out_shape=(jax.ShapeDtypeStruct((B,), jnp.int32),
-                   jax.ShapeDtypeStruct((B,), jnp.float32)),
+        grid=(nv,),
+        in_specs=[pl.BlockSpec((B, bv), lambda vi: (0, vi))],
+        out_specs=(col, col),
+        out_shape=(jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1), jnp.float32)),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.int32),
+            pltpu.VMEM((B, 1), jnp.float32),
+            pltpu.VMEM((B, 1), jnp.float32),
+            pltpu.VMEM((B, 1), jnp.int32),
         ],
-        compiler_params=compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x)
-    return out
+    return tokens[:, 0], logprobs[:, 0]
 
 
 def _topk_kernel(x_ref, out_ref, top_s, *, k: int, nv: int):
@@ -140,7 +142,7 @@ def topk_values(logits, k: int, *, block_v: int = 1024,
         out_specs=pl.BlockSpec((1, k), lambda b, vi: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, k), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, k), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x)

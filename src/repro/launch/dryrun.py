@@ -2,14 +2,17 @@
 (architecture x input shape) on the production meshes, print
 memory_analysis / cost_analysis, and extract roofline terms.
 
-The device-count env var is set below BEFORE any jax import — jax locks the
-device count on first init. Do not import this module from processes that
-need a 1-device view (tests, benches); run it as __main__:
+The platform and device-count env vars are set below BEFORE any jax
+import — jax locks both on first init. The analysis runs on 512 virtual
+CPU devices, pinned to the CPU so that it never takes a chip this machine
+may have. Do not import this module from processes that need a 1-device
+view (tests, benches); run it as __main__:
 
     python -m repro.launch.dryrun --arch internlm2-1.8b --shape train_4k --mesh single
     python -m repro.launch.dryrun --all --mesh both
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_XLA_EXTRA", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 
@@ -198,6 +201,8 @@ def _main():
                    "--microbatches", str(args.microbatches)]
             print("::", " ".join(cmd), flush=True)
             try:
+                # the child inherits JAX_PLATFORMS=cpu from above and sets
+                # it again on import, so it never takes a chip
                 subprocess.run(cmd, timeout=3300)
             except subprocess.TimeoutExpired:
                 with open(path, "w") as f:

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.data import tokenizer as tok
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.rl import (SamplerConfig, completions_to_text, generate,
                       generate_continuous)
@@ -201,6 +202,11 @@ def serve_elastic(arch: str, prompts_text: list[str], *,
 def _main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the 2-layer float32 smoke variant; "
+                         "--no-reduced serves the published widths in "
+                         "the config's dtype")
     ap.add_argument("--engine", choices=("continuous", "static", "elastic"),
                     default="continuous",
                     help="continuous = fixed-capacity slot-pool engine; "
@@ -287,15 +293,16 @@ def _main():
                     help="--engine elastic --shed: per-request deadline, "
                          "seconds after arrival")
     args = ap.parse_args()
+    enable_compile_cache()
     spec = RolloutSpec.from_args(args)
     prompts = [f"{i}+{i+1}=" for i in range(args.batch)]
     if args.group:
         prompts = [p for p in prompts for _ in range(args.group)]
     if args.engine == "elastic":
         ladder = tuple(int(x) for x in args.ladder.split(","))
-        res = serve_elastic(args.arch, prompts, max_new=args.max_new,
-                            spec=spec, ladder=ladder, shed=args.shed,
-                            deadline_s=args.deadline_s)
+        res = serve_elastic(args.arch, prompts, reduced=args.reduced,
+                            max_new=args.max_new, spec=spec, ladder=ladder,
+                            shed=args.shed, deadline_s=args.deadline_s)
         e = res["elastic"]
         print(f"[elastic] served {len(res['texts'])}/{len(prompts)} "
               f"requests, {res['tokens']} tokens in {res['makespan_s']:.2f}s "
@@ -311,8 +318,8 @@ def _main():
             print(f"  rid={o.rid} [{o.finish_reason}] -> {t!r}")
         return
     if args.engine == "continuous":
-        res = serve_continuous(args.arch, prompts, max_new=args.max_new,
-                               spec=spec)
+        res = serve_continuous(args.arch, prompts, reduced=args.reduced,
+                               max_new=args.max_new, spec=spec)
         extra = (f", slot util {res['slot_utilization']:.0%}, "
                  f"{res['decode_steps']} decode steps")
         if args.prefix_share:
@@ -322,7 +329,8 @@ def _main():
             extra += (f", {res['transfers']} KV transfers "
                       f"({res['transfer_overhead_frac']:.1%} overhead)")
     else:
-        res = serve_batch(args.arch, prompts, max_new=args.max_new)
+        res = serve_batch(args.arch, prompts, reduced=args.reduced,
+                          max_new=args.max_new)
         extra = ""
     print(f"[{args.engine}] served {len(prompts)} requests, {res['tokens']} "
           f"tokens in {res['wall_s']:.2f}s ({res['tok_per_s']:.1f} tok/s"

@@ -1,7 +1,7 @@
 """End-to-end RL post-training driver (single host, real execution).
 
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
-        --reduced --steps 50 --batch 8 --group 4
+        --steps 50 --batch 8 --group 4          # --no-reduced: full widths
 
 Runs the full synchronous on-policy loop the paper schedules:
 rollout (generation) -> reward (verifiable) -> GRPO advantages ->
@@ -50,10 +50,11 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.rl.coexec import (GRPOJob, MuxConfig, build_train_batch,
                              run_coexec, run_pipelined, run_sequential)
-from repro.rl.rewards import make_reward
+from repro.rl.rewards import RewardFn, make_reward
 from repro.rl.stream import run_streaming
 
 __all__ = ["build_train_batch", "run_training"]
@@ -70,7 +71,8 @@ def run_training(arch: str = "internlm2-1.8b", *, reduced: bool = True,
                  kernel_backend: str = "jnp", kv_dtype: str | None = None,
                  slo_bound: float = 2.0,
                  mux: str = "off", mux_staleness: int = 1, jobs: int = 2,
-                 reward: str = "arith", reward_latency: float = 0.0,
+                 reward: str | RewardFn = "arith",
+                 reward_latency: float = 0.0,
                  reward_workers: int = 2, micro_groups: int | None = None,
                  elastic: bool = False,
                  spec=None, carry: bool = False,
@@ -82,7 +84,8 @@ def run_training(arch: str = "internlm2-1.8b", *, reduced: bool = True,
     (see module docstring); ``reward``/``reward_latency`` pick the
     verifier (``rl.rewards.make_reward`` — a nonzero latency wraps it in
     the slow external-verifier stub, the workload ``--mux stream``'s
-    reward pool hides off the critical path).  Returns ``(state,
+    reward pool hides off the critical path); ``reward`` may also be a
+    row-wise verifier callable itself.  Returns ``(state,
     history)`` — or, for ``mux="coexec"``, ``(states, histories)`` dicts
     keyed by job id — plus the :class:`~repro.rl.coexec.MuxReport` when
     ``return_report``.
@@ -91,7 +94,8 @@ def run_training(arch: str = "internlm2-1.8b", *, reduced: bool = True,
 
     cfg = MuxConfig(mode=mux, max_staleness=mux_staleness,
                     reward_workers=reward_workers, micro_groups=micro_groups)
-    reward_fn = make_reward(reward, latency_s=reward_latency, seed=seed)
+    reward_fn = (make_reward(reward, latency_s=reward_latency, seed=seed)
+                 if isinstance(reward, str) else reward)
     if spec is None:
         spec = RolloutSpec(num_slots=num_slots,
                            block_size=engine_block_size, kv_layout=kv,
@@ -138,7 +142,11 @@ def _main():
         description="GRPO post-training with phase-multiplexed execution",
         formatter_class=argparse.ArgumentDefaultsHelpFormatter)
     ap.add_argument("--arch", default="internlm2-1.8b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the 2-layer float32 smoke variant; "
+                         "--no-reduced trains the published widths in the "
+                         "config's dtype")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--group", type=int, default=4)
@@ -232,6 +240,7 @@ def _main():
                          "it when the pool idles (held permits are never "
                          "revoked)")
     args = ap.parse_args()
+    enable_compile_cache()
     from repro.serve import RolloutSpec
     spec = RolloutSpec.from_args(args)
     t0 = time.time()

@@ -701,9 +701,10 @@ class Engine:
         from repro.kernels.ops import resolve_interpret
         model, config = self.model, self.config
         # interpret mode resolved once per engine (at call time relative to
-        # the lazy env/flag override) and baked into the jitted fns
-        interp = (resolve_interpret()
-                  if self.kernel_backend == "pallas" else True)
+        # the lazy env/flag override) and baked into the jitted fns; kept
+        # on the engine so a caller can check the kernels run compiled
+        self.interpret = (resolve_interpret()
+                          if self.kernel_backend == "pallas" else True)
         kv_dtype = None if config.kv_dtype == "auto" else config.kv_dtype
         if self.paged:
             if not hasattr(self, "slots"):
@@ -716,7 +717,7 @@ class Engine:
                 model, config.max_seq_len, config.kv_block_size,
                 config.temperature, config.eos_id,
                 kernel_backend=self.kernel_backend, kv_dtype=kv_dtype,
-                interpret=interp)
+                interpret=self.interpret)
         else:
             if kv_dtype is not None:
                 raise ValueError("kv_dtype requires kv_layout='paged'")
@@ -725,8 +726,19 @@ class Engine:
                                          config.max_seq_len)
             self._fns = _engine_fns(
                 model, config.max_seq_len, config.temperature, config.eos_id,
-                kernel_backend=self.kernel_backend, interpret=interp)
+                kernel_backend=self.kernel_backend,
+                interpret=self.interpret)
         self._admit_fn, self._block = self._fns["admit"], self._fns["block"]
+
+    def decode_hlo(self) -> str:
+        """Compiled HLO text of the decode block at this engine's live
+        shapes.  A ``tpu_custom_call`` in it means the Pallas kernels were
+        compiled for the device rather than interpreted."""
+        args = [self.params, self._last_logits, self.slots.cache]
+        if self.paged:
+            args.append(self.slots.device_tables())
+        args += [self._alive, self._remaining, self._zero_keys]
+        return self._block.lower(*args).compile().as_text()
 
     def set_kernel_backend(self, backend: str) -> None:
         """Switch the decode backend on a drained engine.

@@ -91,7 +91,11 @@ def test_untagged_cross_request_sharing_by_content():
     for r, o, c in zip(reqs, outs, base):
         ref_t, ref_l = reference(m, params, r, max_new=5)
         assert o.tokens == c.tokens == ref_t, o.rid
-        np.testing.assert_allclose(o.logprobs, c.logprobs, atol=0)
+        # the extension (rid 2) is a partial hit: it prefills only its
+        # suffix against the pinned blocks, which sums attention in another
+        # order than a full prefill — 1 ulp (~1e-6) apart on XLA:CPU
+        atol = 1e-5 if o.rid == 2 else 0
+        np.testing.assert_allclose(o.logprobs, c.logprobs, atol=atol)
         np.testing.assert_allclose(o.logprobs, ref_l, atol=1e-5)
     assert eng.radix.misses == 1             # only the first prompt prefills
     assert eng.radix.hits == 1               # the exact repeat
@@ -430,9 +434,9 @@ def test_engine_roundtrip_int8_with_suspended_handle_mid_tree():
     eng = Engine(m, params, cfg)
     eng.submit(Request(rid=0, prompt=prompt.copy(), max_new_tokens=8))
     eng.run()
-    ref_t, _ = reference(m, params,
-                         Request(rid=0, prompt=prompt, max_new_tokens=8),
-                         max_new=8)
+    # int8 KV legitimately drifts from the fp32 reference, so the exact-hit
+    # decode below is held to this int8 engine's own first run
+    ref_t = eng.finished[0].tokens
     # suspend a second request mid-generation so the checkpoint carries a
     # live handle next to the tree pins
     stop = ref_t[2]
