@@ -1,0 +1,33 @@
+"""Tiny sizes at which the benchmark's cells run on the CPU in a test:
+the program's reduced widths (2 layers, d_model 256, vocab 512) and a
+traffic mix cut to a few short requests."""
+
+TINY = dict(hidden_size=256, num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2, intermediate_size=512, vocab_size=512)
+
+# Limits of the output check at these sizes in bfloat16, set the way the
+# cells' own are, from readings on the CPU over seeds 1-6 (the program's
+# largest reading, the control's or a fault's smallest):
+# rollout: logp_gap 0.0040 / fp8 0.0187, altered token 0.51.
+LIMITS = {
+    "rollout-longtail": {"wrong_lengths": 0, "logp_gap": 0.01},
+}
+
+
+def tiny_conf(conf: dict, dtype: str) -> dict:
+    return dict(conf, **TINY, torch_dtype=dtype)
+
+
+def shrink_for(dtype: str, limits: dict | None = None):
+    """A ``run_cell`` seam: cut the loaded cell to tiny sizes in ``dtype``
+    and, when given, hold it to ``limits``."""
+    def shrink(cell):
+        cell.conf = tiny_conf(cell.conf, dtype)
+        t = dict(cell.traffic)
+        t.update(prompts=2, group=2, prompt_lengths=[16, 32], slots=4,
+                 num_kv_blocks=16, max_seq_len=48, check_requests=2)
+        t["response"] = dict(t["response"], max_new=8)
+        cell.traffic = t
+        if limits is not None:
+            cell.limits = dict(limits)
+    return shrink
