@@ -24,6 +24,7 @@ from typing import Callable, Optional
 
 import jax
 
+from repro.core.telemetry import span
 from repro.train.checkpoints import HostStateCache
 
 
@@ -201,16 +202,14 @@ class RollMuxRuntime:
         """Unified :class:`~repro.core.telemetry.MetricsSnapshot` of the
         execution plane: per-pool busy fractions (pool busy time over
         runtime elapsed — the elastic controller's permit-retuning
-        signal) and capacities.  Merges cleanly with engine/router
+        signal).  Merges cleanly with engine/router
         snapshots (dict fields union)."""
         from repro.core.telemetry import MetricsSnapshot
         elapsed = max(time.perf_counter() - self._t0, 1e-9)
         return MetricsSnapshot(
             source="runtime",
             pool_busy_frac={name: min(p.busy_time / elapsed, 1.0)
-                            for name, p in self.pools.items()},
-            pool_capacity={name: p.capacity
-                           for name, p in self.pools.items()})
+                            for name, p in self.pools.items()})
 
     def runtime_hook(self, fn: Callable) -> Callable:
         """@rollmux.runtime_hook — called as fn(job_id, phase, event)."""
@@ -240,26 +239,27 @@ class RollMuxRuntime:
                 st = self.stats.setdefault(f"{job_id}:{pname}", PhaseStats())
                 t_req = time.perf_counter()
                 p = self.pool(pool)
-                p.acquire()                       # run permit (intra-group FIFO)
-                try:
+                with self._held(p, job_id):   # run permit (intra-group FIFO)
                     t_start = time.perf_counter()
                     st.wait_time += t_start - t_req
                     self._emit(job_id, pname, "start")
-                    state, sw = self.cache.restore(key)
-                    if state is None:             # cold start
-                        t0 = time.perf_counter()
-                        if init_fn is None:
-                            raise RuntimeError(
-                                f"no cached state and no init_fn for {key}")
-                        state = init_fn()
-                        sw = time.perf_counter() - t0
-                        st.cold_starts += 1
-                    else:
-                        st.warm_starts += 1
+                    with span("mux.restore", job=job_id):
+                        state, sw = self.cache.restore(key)
+                        if state is None:             # cold start
+                            t0 = time.perf_counter()
+                            if init_fn is None:
+                                raise RuntimeError(f"no cached state and "
+                                                   f"no init_fn for {key}")
+                            state = init_fn()
+                            sw = time.perf_counter() - t0
+                            st.cold_starts += 1
+                        else:
+                            st.warm_starts += 1
                     st.switch_time += sw
                     state, out = fn(state, *args, **kwargs)
                     jax.block_until_ready(jax.tree.leaves(state)[:1])
-                    self.cache.offload(key, state)  # suspend: data plane out
+                    with span("mux.offload", job=job_id):
+                        self.cache.offload(key, state)  # suspend: data out
                     t_end = time.perf_counter()
                     st.run_time += t_end - t_start
                     st.runs += 1
@@ -268,8 +268,6 @@ class RollMuxRuntime:
                     p.busy_time += t_end - t_start
                     self._emit(job_id, pname, "end")
                     return out
-                finally:
-                    p.release()
 
             wrapped.pool_name = pool
             wrapped.phase_name = pname
@@ -285,14 +283,24 @@ class RollMuxRuntime:
         single-job trainer, where params are handed over directly instead
         of through the actor cache)."""
         p = self.pool(pool, capacity)
+        with self._held(p, who.split(":")[0]):
+            t_start = time.perf_counter()
+            try:
+                yield p
+            finally:
+                t_end = time.perf_counter()
+                p.timeline.append((who, t_start - self._t0, t_end - self._t0))
+                p.busy_time += t_end - t_start
+
+    @contextlib.contextmanager
+    def _held(self, p: PermitPool, job_id: str):
+        """Hold ``p``'s run permit for the body, under a ``mux.<pool>``
+        span carrying ``job`` in the profiler's trace."""
         p.acquire()
-        t_start = time.perf_counter()
         try:
-            yield p
+            with span(f"mux.{p.name}", job=job_id):
+                yield
         finally:
-            t_end = time.perf_counter()
-            p.timeline.append((who, t_start - self._t0, t_end - self._t0))
-            p.busy_time += t_end - t_start
             p.release()
 
     def seed_state(self, job_id: str, pool: str, state) -> None:

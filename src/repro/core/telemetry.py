@@ -16,11 +16,28 @@ controller (``serve.elastic``) and the benchmarks consume *only* this API;
 legacy attribute access (``engine.stats`` / ``router.stats``) survives via
 a warn-once :class:`DeprecationWarning` shim (same pattern as the PR 8
 ``RolloutSpec`` migration).
+
+:func:`span` is the other half: named host spans at the boundaries of
+the engine's scheduler tick and the mux's permits, written into the
+``jax.profiler`` trace beside the device's operations and on its clock.
 """
 from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass, field, fields
+
+import jax
+
+# every span the program opens starts with one of these
+SPAN_PREFIXES = ("engine.", "mux.")
+
+
+def span(name: str, **meta):
+    """A host span named ``name`` in the profiler's trace, with ``meta``
+    (request or job ids) as the event's stats, so that the name stays the
+    same for every request.  With the profiler stopped it is a no-op
+    annotation."""
+    return jax.profiler.TraceAnnotation(name, **meta)
 
 
 def warn_legacy_once(flag: list, message: str) -> None:
@@ -44,19 +61,17 @@ class MetricsSnapshot:
     * **peaks** (max): high-water marks — ``peak_active``,
       ``peak_kv_blocks``.
     * **gauges** (other wins when set): instantaneous occupancy —
-      ``queue_depth``, ``num_active``, ``kv_blocks_in_use``, … .  Merging
+      ``queue_depth``, ``num_active``, ``suspended``, … .  Merging
       a router's decode + prefill snapshots sums these *before* they meet
       this rule (the router does that itself), so cross-component merges
       just keep the freshest reading.
-    * **dicts** (union, other wins per key): per-pool busy fractions and
-      capacities, per-class attainment.
+    * **dicts** (union, other wins per key): per-pool busy fractions.
     """
 
     source: str = ""
 
     # -- queueing / slot occupancy (gauges except the peaks/counters noted)
     queue_depth: int = 0                 # gauge: waiting requests
-    rejected_submits: int = 0            # counter
     num_slots: int = 0                   # gauge: configured decode slots
     num_active: int = 0                  # gauge: live decode slots
     peak_active: int = 0                 # peak
@@ -66,51 +81,39 @@ class MetricsSnapshot:
     steps: int = 0
     decode_time_s: float = 0.0
     prefills: int = 0
-    recorded_tokens: int = 0
     generated_tokens: int = 0
 
     # -- KV block pool
-    kv_blocks_total: int = 0             # gauge: pool size
-    kv_blocks_in_use: int = 0            # gauge
     peak_kv_blocks: int = 0              # peak
 
-    # -- prefix sharing (counters; pinned_blocks is a gauge)
+    # -- prefix sharing (counters)
     prefix_hits: int = 0
     prefix_partial_hits: int = 0
-    prefix_misses: int = 0
-    prefix_evictions: int = 0
-    prefix_snapshots: int = 0            # gauge: live boundary snapshots
     snapshot_demotions: int = 0          # counter: TTL demotions
     blocks_saved: int = 0
-    pinned_blocks: int = 0               # gauge: radix-held blocks
 
     # -- suspend/resume + disaggregation
-    adoptions: int = 0                   # counter
     suspends: int = 0                    # counter
     resumes: int = 0                     # counter
     suspended: int = 0                   # gauge: live suspended handles
     transfers: int = 0                   # counter
     transfer_time_s: float = 0.0         # counter
     transferred_blocks: int = 0          # counter
-    transfer_backlog: int = 0            # gauge: handles awaiting adoption
     kv_routed: int = 0                   # counter
 
-    # -- admission control (counters; attainment is a dict gauge)
+    # -- admission control (counters)
     sheds: int = 0
     degrades: int = 0
-    attainment: dict = field(default_factory=dict)   # class -> met fraction
 
-    # -- runtime permit pools (dict gauges)
+    # -- runtime permit pools (dict gauge)
     pool_busy_frac: dict = field(default_factory=dict)
-    pool_capacity: dict = field(default_factory=dict)
 
     weight_version: int = 0              # gauge
 
     _PEAKS = ("peak_active", "peak_kv_blocks")
-    _GAUGES = ("queue_depth", "num_slots", "num_active", "kv_blocks_total",
-               "kv_blocks_in_use", "prefix_snapshots", "pinned_blocks",
-               "suspended", "transfer_backlog", "weight_version")
-    _DICTS = ("attainment", "pool_busy_frac", "pool_capacity")
+    _GAUGES = ("queue_depth", "num_slots", "num_active", "suspended",
+               "weight_version")
+    _DICTS = ("pool_busy_frac",)
 
     # -- derived ------------------------------------------------------
     @property
@@ -123,10 +126,6 @@ class MetricsSnapshot:
         """Useful tokens per slot-step of capacity offered (matches
         ``EngineStats.slot_utilization``)."""
         return self.generated_tokens / max(self.slot_steps, 1)
-
-    @property
-    def kv_block_utilization(self) -> float:
-        return self.kv_blocks_in_use / max(self.kv_blocks_total, 1)
 
     @property
     def transfer_overhead_frac(self) -> float:
@@ -182,6 +181,5 @@ class MetricsSnapshot:
         reports."""
         d = {f.name: getattr(self, f.name) for f in fields(self)}
         d.update(time_per_token=self.time_per_token,
-                 slot_utilization=self.slot_utilization,
-                 kv_block_utilization=self.kv_block_utilization)
+                 slot_utilization=self.slot_utilization)
         return d

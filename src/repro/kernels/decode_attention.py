@@ -129,6 +129,7 @@ def decode_attention(q, k, v, length, *, window=None, block_k: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(lengths, win, qt, kt, vt)
     return out.reshape(B, H, D)
 
@@ -256,5 +257,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(tbl, lengths, win, *operands)
     return out.reshape(B, H, D)

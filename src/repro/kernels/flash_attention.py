@@ -105,5 +105,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return jnp.moveaxis(out[:, :, :S], 1, 2)

@@ -96,5 +96,6 @@ def mamba2_scan(r, k, v, log_w, *, chunk: int = 64, interpret: bool = True):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="mamba2_scan",
     )(rt, kt, vt, lwt)
     return jnp.moveaxis(y[:, :, :S], 1, 2), state

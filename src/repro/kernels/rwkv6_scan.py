@@ -104,5 +104,6 @@ def rwkv6_scan(r, k, v, log_w, u, *, chunk: int = 64, interpret: bool = True):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="rwkv6_scan",
     )(rt, kt, vt, lwt, u)
     return jnp.moveaxis(y[:, :, :S], 1, 2), state

@@ -93,6 +93,7 @@ def greedy_sample(logits, *, block_v: int = 1024, interpret: bool = True):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="greedy_sample",
     )(x)
     return tokens[:, 0], logprobs[:, 0]
 
@@ -145,6 +146,7 @@ def topk_values(logits, k: int, *, block_v: int = 1024,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="topk_values",
     )(x)
 
 

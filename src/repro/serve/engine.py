@@ -70,7 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.telemetry import MetricsSnapshot, warn_legacy_once
+from repro.core.telemetry import MetricsSnapshot, span, warn_legacy_once
 from repro.data import tokenizer as tok
 from repro.models.attention import gather_blocks
 from repro.serve.blocks import blocks_for
@@ -835,7 +835,6 @@ class Engine:
         snap = MetricsSnapshot(
             source="engine",
             queue_depth=len(self.queue),
-            rejected_submits=self.queue.rejected,
             num_slots=self.config.num_slots,
             num_active=len(self._active),
             peak_active=s.peak_active,
@@ -843,27 +842,17 @@ class Engine:
             steps=s.steps,
             decode_time_s=s.decode_time_s,
             prefills=s.prefills,
-            recorded_tokens=s.recorded_tokens,
             generated_tokens=s.recorded_tokens,
             peak_kv_blocks=s.peak_kv_blocks,
             prefix_hits=s.prefix_hits,
             prefix_partial_hits=s.prefix_partial_hits,
             blocks_saved=s.blocks_saved,
-            adoptions=s.adoptions,
             suspends=s.suspends,
             resumes=s.resumes,
             suspended=len(self.suspended),
             weight_version=self.weight_version)
-        if self.paged:
-            snap.kv_blocks_total = self.slots.alloc.num_blocks
-            snap.kv_blocks_in_use = self.slots.blocks_in_use
         if self.radix is not None:
-            rs = self.radix.stats
-            snap.prefix_misses = rs["misses"]
-            snap.prefix_evictions = rs["evictions"]
-            snap.pinned_blocks = rs["pinned_blocks"]
-            snap.prefix_snapshots = rs["snapshots"]
-            snap.snapshot_demotions = rs["snapshot_demotions"]
+            snap.snapshot_demotions = self.radix.stats["snapshot_demotions"]
         return snap
 
     # ---- scheduler ---------------------------------------------------------
@@ -933,47 +922,14 @@ class Engine:
                                             self.slots.blocks_in_use)
 
     def _admit_one(self, req: Request) -> None:
-        """Prefill (or share) one picked request into a free slot."""
-        prompt_dev = jnp.asarray(req.prompt)[None]
-        budget = jnp.asarray(req.max_new_tokens, jnp.int32)
-        shared_blocks = 0
-        if not self.paged:
-            slot = self.slots.assign(req.rid)
-            (self.slots.cache, self._last_logits, self._alive,
-             self._remaining) = self._admit_fn(
-                self.params, prompt_dev, req.frontend,
-                self.slots.cache, jnp.asarray(slot, jnp.int32),
-                self._last_logits, self._alive, self._remaining, budget)
-        else:
-            m = self._match(req, count=True)
-            if m is not None and m.exact:
-                slot = self._admit_shared_exact(req, m, budget)
-                shared_blocks = m.n_shared
-            elif m is not None and m.n_shared > 0:
-                slot = self._admit_shared_prefix(req, m, prompt_dev, budget)
-                shared_blocks = m.n_shared
-            else:
-                slot = self.slots.assign(req.rid, prompt_len=req.prompt_len,
-                                         total_budget=req.total_budget)
-                row = self.slots.device_tables()[slot]
-                if self.radix is not None and req.frontend is None:
-                    # donor path: split prefill + scatter so the radix
-                    # path (blocks + tail/slot-row snapshot) can register
-                    logits, one = self._fns["prefill"](
-                        self.params, prompt_dev, req.frontend)
-                    (self.slots.cache, self._last_logits, self._alive,
-                     self._remaining) = self._fns["scatter"](
-                        logits, one, self.slots.cache, row,
-                        jnp.asarray(slot, jnp.int32), self._last_logits,
-                        self._alive, self._remaining, budget)
-                    self._register_prefix(req, slot, logits, one)
-                else:
-                    (self.slots.cache, self._last_logits, self._alive,
-                     self._remaining) = self._admit_fn(
-                        self.params, prompt_dev, req.frontend,
-                        self.slots.cache, row, jnp.asarray(slot, jnp.int32),
-                        self._last_logits, self._alive, self._remaining,
-                        budget)
+        """Prefill (or share) one picked request into a free slot, under an
+        ``engine.share`` span for an exact radix hit and ``engine.prefill``
+        for every path that runs the model."""
+        m = self._match(req, count=True) if self.paged else None
+        exact = m is not None and m.exact
+        with span("engine.share" if exact else "engine.prefill",
+                  rid=req.rid):
+            slot, shared_blocks = self._admit_slot(req, m)
         self._host_index[slot] = req.prompt_len
         self._slot_version[slot] = self.weight_version
         self._seed_tokens[slot] = 0
@@ -986,6 +942,46 @@ class Engine:
         self._active[slot] = (req, out)
         self._stats.prefills += 1
         self._stats.blocks_saved += shared_blocks
+
+    def _admit_slot(self, req: Request, m) -> tuple[int, int]:
+        """Place ``req`` (radix match ``m``) in a slot; returns the slot and
+        the number of prefix-shared blocks it holds."""
+        prompt_dev = jnp.asarray(req.prompt)[None]
+        budget = jnp.asarray(req.max_new_tokens, jnp.int32)
+        if not self.paged:
+            slot = self.slots.assign(req.rid)
+            (self.slots.cache, self._last_logits, self._alive,
+             self._remaining) = self._admit_fn(
+                self.params, prompt_dev, req.frontend,
+                self.slots.cache, jnp.asarray(slot, jnp.int32),
+                self._last_logits, self._alive, self._remaining, budget)
+            return slot, 0
+        if m is not None and m.exact:
+            return self._admit_shared_exact(req, m, budget), m.n_shared
+        if m is not None and m.n_shared > 0:
+            return (self._admit_shared_prefix(req, m, prompt_dev, budget),
+                    m.n_shared)
+        slot = self.slots.assign(req.rid, prompt_len=req.prompt_len,
+                                 total_budget=req.total_budget)
+        row = self.slots.device_tables()[slot]
+        if self.radix is not None and req.frontend is None:
+            # donor path: split prefill + scatter so the radix path
+            # (blocks + tail/slot-row snapshot) can register
+            logits, one = self._fns["prefill"](
+                self.params, prompt_dev, req.frontend)
+            (self.slots.cache, self._last_logits, self._alive,
+             self._remaining) = self._fns["scatter"](
+                logits, one, self.slots.cache, row,
+                jnp.asarray(slot, jnp.int32), self._last_logits,
+                self._alive, self._remaining, budget)
+            self._register_prefix(req, slot, logits, one)
+        else:
+            (self.slots.cache, self._last_logits, self._alive,
+             self._remaining) = self._admit_fn(
+                self.params, prompt_dev, req.frontend,
+                self.slots.cache, row, jnp.asarray(slot, jnp.int32),
+                self._last_logits, self._alive, self._remaining, budget)
+        return slot, 0
 
     def _register_prefix(self, req: Request, slot: int, logits, one) -> None:
         """Record the donor's full prompt blocks + admit snapshot."""
@@ -1083,43 +1079,45 @@ class Engine:
         :meth:`can_admit_prefilled` — like ``SlotManager.assign``, this
         raises rather than queues when the pool is full."""
         self._validate_stop_tokens(req)
-        budget = jnp.asarray(req.max_new_tokens, jnp.int32)
-        if not self.paged:
-            slot = self.slots.assign(req.rid)
-            (self.slots.cache, self._last_logits, self._alive,
-             self._remaining) = self._fns["scatter"](
-                logits, one, self.slots.cache, jnp.asarray(slot, jnp.int32),
-                self._last_logits, self._alive, self._remaining, budget)
-        else:
-            slot = self.slots.assign(req.rid, prompt_len=req.prompt_len,
-                                     total_budget=req.total_budget)
-            row = self.slots.device_tables()[slot]
-            (self.slots.cache, self._last_logits, self._alive,
-             self._remaining) = self._fns["scatter"](
-                logits, one, self.slots.cache, row,
-                jnp.asarray(slot, jnp.int32), self._last_logits,
-                self._alive, self._remaining, budget)
-            if self.radix is not None and req.frontend is None:
-                # register the adopted prompt — for multi-turn resume()
-                # this is the episode's whole history, so sibling
-                # rollouts and turn k+1 match turn k's blocks
-                self._register_prefix(req, slot, logits, one)
-            self._stats.peak_kv_blocks = max(self._stats.peak_kv_blocks,
-                                            self.slots.blocks_in_use)
-        self._host_index[slot] = req.prompt_len
-        self._slot_version[slot] = self.weight_version
-        self._seed_tokens[slot] = 0
-        out = RequestOutput(rid=req.rid, prompt=req.prompt,
-                            prefill_step=self._stats.steps,
-                            arrival_time=req.arrival_time,
-                            priority=req.priority, deadline=req.deadline,
-                            job_id=req.job_id)
-        self._active[slot] = (req, out)
-        self._stats.prefills += 1
-        self._stats.adoptions += 1
-        self._stats.peak_active = max(self._stats.peak_active,
-                                     len(self._active))
-        return slot
+        with span("engine.adopt", rid=req.rid):
+            budget = jnp.asarray(req.max_new_tokens, jnp.int32)
+            if not self.paged:
+                slot = self.slots.assign(req.rid)
+                (self.slots.cache, self._last_logits, self._alive,
+                 self._remaining) = self._fns["scatter"](
+                    logits, one, self.slots.cache,
+                    jnp.asarray(slot, jnp.int32), self._last_logits,
+                    self._alive, self._remaining, budget)
+            else:
+                slot = self.slots.assign(req.rid, prompt_len=req.prompt_len,
+                                         total_budget=req.total_budget)
+                row = self.slots.device_tables()[slot]
+                (self.slots.cache, self._last_logits, self._alive,
+                 self._remaining) = self._fns["scatter"](
+                    logits, one, self.slots.cache, row,
+                    jnp.asarray(slot, jnp.int32), self._last_logits,
+                    self._alive, self._remaining, budget)
+                if self.radix is not None and req.frontend is None:
+                    # register the adopted prompt — for multi-turn resume()
+                    # this is the episode's whole history, so sibling
+                    # rollouts and turn k+1 match turn k's blocks
+                    self._register_prefix(req, slot, logits, one)
+                self._stats.peak_kv_blocks = max(self._stats.peak_kv_blocks,
+                                                self.slots.blocks_in_use)
+            self._host_index[slot] = req.prompt_len
+            self._slot_version[slot] = self.weight_version
+            self._seed_tokens[slot] = 0
+            out = RequestOutput(rid=req.rid, prompt=req.prompt,
+                                prefill_step=self._stats.steps,
+                                arrival_time=req.arrival_time,
+                                priority=req.priority, deadline=req.deadline,
+                                job_id=req.job_id)
+            self._active[slot] = (req, out)
+            self._stats.prefills += 1
+            self._stats.adoptions += 1
+            self._stats.peak_active = max(self._stats.peak_active,
+                                         len(self._active))
+            return slot
 
     def _finalize(self, slot: int) -> None:
         req, out = self._active[slot]
@@ -1151,27 +1149,46 @@ class Engine:
         ``block_size`` decode steps for all slots.  Returns the number of
         decode steps executed — ``0`` means *no work* (nothing admissible
         queued and no live slot), so drivers waiting on late submissions
-        can sleep instead of spinning (see :func:`run_trace`)."""
-        self._admit()
-        if not self._active:
-            if self.queue:
-                # nothing live, requests waiting, nothing admissible — and
-                # with an empty engine nothing will ever change that: the
-                # admission gate depends only on engine state.  A per-job
-                # token budget smaller than a single request's decode
-                # budget is the one way to get here; fail loud over
-                # spinning forever.
-                raise RuntimeError(
-                    f"admission stalled: {len(self.queue)} waiting, 0 "
-                    f"active — check policy token budgets / pool sizing")
-            return 0
+        can sleep instead of spinning (see :func:`run_trace`).
+
+        The tick is an ``engine.step`` span holding, in order,
+        ``engine.admit``, ``engine.dispatch`` (the decode block's launch),
+        ``engine.wait`` (the host's one wait on the device) and
+        ``engine.record`` (the outputs into each request)."""
+        with span("engine.step"):
+            with span("engine.admit"):
+                self._admit()
+            if not self._active:
+                if self.queue:
+                    # nothing live, requests waiting, nothing admissible —
+                    # and with an empty engine nothing will ever change
+                    # that: the admission gate depends only on engine
+                    # state.  A per-job token budget smaller than a single
+                    # request's decode budget is the one way to get here;
+                    # fail loud over spinning forever.
+                    raise RuntimeError(
+                        f"admission stalled: {len(self.queue)} waiting, 0 "
+                        f"active — check policy token budgets / pool sizing")
+                return 0
+            K = self.config.block_size
+            with span("engine.dispatch"):
+                t_decode = time.perf_counter()
+                out = self._dispatch(K)
+            with span("engine.wait"):
+                fetched = jax.device_get((*out, self._alive, self._remaining))
+            t_decode = time.perf_counter() - t_decode
+            with span("engine.record"):
+                self._record(K, t_decode, *fetched)
+            return K
+
+    def _dispatch(self, K: int):
+        """Launch the decode block of ``K`` steps over every slot; returns
+        its per-step outputs, still on the device."""
         if self.config.temperature == 0:
             keys = self._zero_keys          # unused by greedy sampling
         else:
             self._rng, sub = jax.random.split(self._rng)
-            keys = jax.random.split(sub, self.config.block_size)
-        K = self.config.block_size
-        t_decode = time.perf_counter()
+            keys = jax.random.split(sub, K)
         if self.paged:
             # materialize blocks this decode block will write into
             # (allocation stays within each request's admit-time reservation)
@@ -1191,9 +1208,13 @@ class Engine:
                 self._alive, self._remaining, keys)
         for slot in self._active:
             self._host_index[slot] += K
-        toks, logps, recs, alive, remaining = jax.device_get(
-            (*out, self._alive, self._remaining))
-        t_decode = time.perf_counter() - t_decode
+        return out
+
+    def _record(self, K: int, t_decode: float, toks, logps, recs, alive,
+                remaining) -> None:
+        """Account the decode block that took ``t_decode`` seconds and
+        append its tokens and log-probabilities to each live request,
+        finalising or suspending those it ended."""
         self._stats.decode_time_s += t_decode
         # engine-measured service time straight into the admission policy:
         # K decode steps just took t_decode (every live slot advanced one
@@ -1242,7 +1263,6 @@ class Engine:
                 self._newly_suspended.append(sreq)
             elif (not alive[slot]) or remaining[slot] <= 0:
                 self._finalize(slot)
-        return K
 
     def run(self, *, max_ticks: Optional[int] = None,
             should_yield=None) -> list[RequestOutput]:

@@ -242,7 +242,7 @@ class DisaggRouter:
         """One merged :class:`~repro.core.telemetry.MetricsSnapshot` across
         both planes: the decode engine's snapshot, prefill-side counters
         summed over all prefill engines, and the router's own transfer
-        counters + backlog gauge."""
+        counters."""
         snap = self.decode.metrics()
         snap.source = "router"
         for pe in self.prefills:
@@ -252,18 +252,12 @@ class DisaggRouter:
             snap.prefix_partial_hits += s.prefix_partial_hits
             snap.blocks_saved += s.blocks_saved
             snap.queue_depth += len(pe.queue)
-            snap.rejected_submits += pe.queue.rejected
             if pe.radix is not None:
-                rs = pe.radix.stats
-                snap.prefix_misses += rs["misses"]
-                snap.prefix_evictions += rs["evictions"]
-                snap.pinned_blocks += rs["pinned_blocks"]
-                snap.prefix_snapshots += rs["snapshots"]
-                snap.snapshot_demotions += rs["snapshot_demotions"]
+                snap.snapshot_demotions += \
+                    pe.radix.stats["snapshot_demotions"]
         snap.transfers = self._stats.transfers
         snap.transfer_time_s = self._stats.transfer_time_s
         snap.transferred_blocks = self._stats.transferred_blocks
-        snap.transfer_backlog = len(self.pending_transfer)
         snap.kv_routed = self._stats.kv_routed
         return snap
 

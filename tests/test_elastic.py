@@ -190,8 +190,7 @@ def test_stats_shims_warn_once_and_metrics_is_silent():
 def test_metrics_snapshot_merge_rules():
     a = MetricsSnapshot(source="engine", steps=10, decode_time_s=1.0,
                         peak_active=3, queue_depth=5, num_slots=4,
-                        pool_busy_frac={"rollout": 0.5},
-                        attainment={"interactive": 1.0})
+                        pool_busy_frac={"rollout": 0.5, "reward": 0.2})
     b = MetricsSnapshot(source="runtime", steps=2, decode_time_s=0.5,
                         peak_active=2, queue_depth=0, num_slots=0,
                         pool_busy_frac={"rollout": 0.9, "train": 0.1})
@@ -200,8 +199,8 @@ def test_metrics_snapshot_merge_rules():
     assert m.steps == 12                          # counters sum
     assert m.peak_active == 3                     # peaks max
     assert m.queue_depth == 5 and m.num_slots == 4  # gauges: b unset -> a
-    assert m.pool_busy_frac == {"rollout": 0.9, "train": 0.1}  # dict union
-    assert m.attainment == {"interactive": 1.0}
+    # dicts: union, b wins per key
+    assert m.pool_busy_frac == {"rollout": 0.9, "train": 0.1, "reward": 0.2}
     # gauge where b carries a reading: b wins
     c = a.merge(MetricsSnapshot(queue_depth=1))
     assert c.queue_depth == 1

@@ -3,10 +3,11 @@ published widths.
 
 Interpret mode runs every kernel through XLA on the CPU and so cannot see
 the TPU compiler's tiling and VMEM rules; these compiles can, at no chip
-time.  Each kernel of the serving main path, the paged engine's jitted
-decode block, and the GRPO train step at ``chip_smoke``'s depth cut are
-compiled for one chip of a ``v5e:2x2`` topology.  Nothing runs, so nothing
-here says anything about results or times.
+time.  Each kernel of the serving main path (its lowered call carrying
+the kernel's stable name), the paged engine's jitted decode block, and
+the GRPO train step at ``chip_smoke``'s depth cut are compiled for one
+chip of a ``v5e:2x2`` topology.  Nothing runs, so nothing here says
+anything about results or times.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports every
@@ -64,6 +65,7 @@ def _compile(fn, *args):
 def _kernel_case(name):
     from repro.kernels.decode_attention import (decode_attention,
                                                 paged_decode_attention)
+    from repro.kernels.flash_attention import flash_attention
     from repro.kernels.sampling import greedy_sample
     cfg = get_config(ARCH)
     H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
@@ -93,16 +95,32 @@ def _kernel_case(name):
         rows = 1 if name.endswith("b1") else B
         return (lambda x: greedy_sample(x, interpret=False),
                 [S_((rows, VOCAB), jnp.float32)])
+    if name == "flash_bf16":
+        q, kv = S_((1, S, H, D), bf16), S_((1, S, Hkv, D), bf16)
+        return (lambda *a: flash_attention(*a, interpret=False),
+                [q, kv, kv])
     raise KeyError(name)
 
 
-@pytest.mark.parametrize("name", ["paged_f32", "paged_bf16", "paged_int8",
-                                  "decode_bf16", "greedy_sample_b1",
-                                  "greedy_sample"])
+# each case's kernel, by the stable name its pallas_call carries
+KERNEL_NAMES = {"paged_f32": "paged_decode_attention",
+                "paged_bf16": "paged_decode_attention",
+                "paged_int8": "paged_decode_attention",
+                "decode_bf16": "decode_attention",
+                "greedy_sample_b1": "greedy_sample",
+                "greedy_sample": "greedy_sample",
+                "flash_bf16": "flash_attention"}
+
+
+@pytest.mark.parametrize("name", list(KERNEL_NAMES))
 def test_kernel_compiles_for_v5e(name, sds):
     fn, args = _kernel_case(name)
-    compiled = _compile(fn, *sds(args))
-    assert "tpu_custom_call" in compiled.as_text()
+    lowered = jax.jit(fn).lower(*sds(args))
+    assert f'kernel_name = "{KERNEL_NAMES[name]}"' in lowered.as_text()
+    compiled = lowered.compile().as_text()
+    assert "tpu_custom_call" in compiled
+    # the operation a profile shows is named after the kernel
+    assert f"%{KERNEL_NAMES[name]}." in compiled
 
 
 @pytest.mark.parametrize("kv_dtype", [None, "int8"])
