@@ -388,7 +388,10 @@ def stack_kernel_decode_step(p, cfg: ModelConfig, token, cache, *,
 
     Dead slots (table rows all 0 / ``index`` past the stripe) write into
     the null block or fall off the stripe — don't-care positions attention
-    masks out, same as the vmapped jnp path.  Returns
+    masks out, same as the vmapped jnp path.  In the paged case a dead
+    slot (its table's first entry the null block: ``release`` zeroes the
+    row, a live slot always holds its prompt's first block) is passed to
+    the kernel as length 0, so it costs no KV reads.  Returns
     ``(logits (N, V) f32, cache')``.
     """
     if not kernel_supported(cfg):
@@ -422,6 +425,7 @@ def stack_kernel_decode_step(p, cfg: ModelConfig, token, cache, *,
         blk = jnp.minimum(index // bs, MB - 1)
         pid = tables[rows, blk]            # 0 (null block) when dead/overrun
         off = index % bs
+        lengths = jnp.where(tables[:, 0] == 0, 0, index + 1)
 
     def body(x, xs):
         lp, k_l, v_l, ks_l, vs_l, theta, window = xs
@@ -447,7 +451,7 @@ def stack_kernel_decode_step(p, cfg: ModelConfig, token, cache, *,
             else:
                 k_l = k_l.at[pid, off].set(kr.astype(k_l.dtype))
                 v_l = v_l.at[pid, off].set(vr.astype(v_l.dtype))
-            o = paged_decode_attention(q[:, 0], k_l, v_l, tables, index + 1,
+            o = paged_decode_attention(q[:, 0], k_l, v_l, tables, lengths,
                                        window=window, k_scale=ks_l,
                                        v_scale=vs_l, interpret=interpret)
         a = jnp.einsum("bshe,hed->bsd", o[:, None], lp["attn"]["wo"])

@@ -113,6 +113,90 @@ def test_mamba2_scan_sweep(B, S, H, N, hd, chunk, rng_key):
                                rtol=2e-3, atol=5e-4)
 
 
+def _paged_case(B, H, Hkv, D, bs, MB, lengths, *, dtype=jnp.float32,
+                quant=False, shared=0, seed=0):
+    """Pools, disjoint block tables (zero tails) and lengths for one paged
+    decode case; ``shared`` leading blocks of row 0's table reappear at the
+    head of every other live row's (one prompt prefix, many rows)."""
+    NB = B * MB + 1                       # pool + null block
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (B, H, D), dtype)
+    scales = {}
+    if quant:
+        k_pool, v_pool = (jax.random.randint(
+            kk, (NB, bs, Hkv, D), -127, 128, jnp.int32).astype(jnp.int8)
+            for kk in ks[1:3])
+        scales = {"k_scale": jax.random.uniform(ks[3], (NB, bs),
+                                                minval=1e-3, maxval=2e-2),
+                  "v_scale": jax.random.uniform(ks[4], (NB, bs),
+                                                minval=1e-3, maxval=2e-2)}
+    else:
+        k_pool = jax.random.normal(ks[1], (NB, bs, Hkv, D), dtype)
+        v_pool = jax.random.normal(ks[2], (NB, bs, Hkv, D), dtype)
+    perm = np.random.default_rng(seed).permutation(np.arange(1, NB))
+    tables = np.zeros((B, MB), np.int32)
+    for b, n in enumerate(lengths):
+        nb = -(-n // bs)
+        tables[b, :nb] = perm[b * MB:b * MB + nb]
+        if b and n and shared:
+            tables[b, :shared] = tables[0, :shared]
+    return q, k_pool, v_pool, tables, np.asarray(lengths, np.int32), scales
+
+
+# (B, H, Hkv, D, bs, MB, lengths, options).  K pages of 64 KB in float32
+# make P = 8 pages (128 positions) a chunk unless a case says otherwise;
+# cases of one shape share one compile.
+PAGED_CASES = {
+    "ragged": (4, 16, 8, 128, 16, 24, (300, 17, 129, 200), {}),
+    "full_table": (4, 16, 8, 128, 16, 24, (384, 100, 0, 5), {}),
+    "length_one": (4, 16, 8, 128, 16, 24, (1, 1, 250, 0), {}),
+    "shared_prefix": (4, 16, 8, 128, 16, 24, (300, 290, 0, 310),
+                      {"shared": 12}),
+    "all_dead": (4, 16, 8, 128, 16, 24, (0, 0, 0, 0), {}),
+    "window": (4, 16, 8, 128, 16, 24, (300, 40, 383, 0), {"window": 70}),
+    "int8": (4, 16, 8, 128, 16, 40, (600, 0, 33, 129),
+             {"quant": True, "dtype": jnp.bfloat16}),
+    "bf16": (4, 16, 8, 128, 16, 20, (300, 0, 129, 17),
+             {"dtype": jnp.bfloat16}),
+    "mha": (4, 4, 4, 128, 16, 40, (600, 0, 129, 1), {}),
+    "one_kv_head": (4, 4, 1, 128, 64, 24, (1100, 129, 0, 64), {}),
+    "head_dim_256": (4, 8, 4, 256, 16, 12, (150, 0, 65, 192), {}),
+    "table_narrower_than_chunk": (3, 4, 2, 32, 8, 3, (24, 5, 0), {}),
+}
+
+
+@pytest.mark.parametrize("case", list(PAGED_CASES))
+def test_paged_decode_attention_cases(case):
+    """The paged kernel against its gather-then-attend oracle on every live
+    row, and finite zeros on every row of length 0."""
+    from repro.kernels.decode_attention import (_pages_per_step,
+                                                paged_decode_attention)
+    B, H, Hkv, D, bs, MB, lengths, opts = PAGED_CASES[case]
+    opts = dict(opts)
+    window = opts.pop("window", None)
+    q, k_pool, v_pool, tables, lengths, scales = _paged_case(
+        B, H, Hkv, D, bs, MB, lengths, **opts)
+    pp = _pages_per_step(bs, Hkv, D, k_pool.dtype.itemsize, MB)
+    if case == "table_narrower_than_chunk":
+        assert pp == MB < _pages_per_step(bs, Hkv, D, 4, 10 ** 6)
+    elif case != "all_dead":             # rows of more than one chunk
+        assert max(-(-n // bs) for n in lengths) > pp
+    if case == "ragged":
+        assert all(n % (pp * bs) for n in lengths)
+    if case == "full_table":
+        assert lengths.max() == MB * bs
+    out = np.asarray(paged_decode_attention(
+        q, k_pool, v_pool, tables, lengths, window=window, **scales),
+        np.float32)
+    expect = np.asarray(ref.paged_decode_attention_ref(
+        q, k_pool, v_pool, tables, lengths, window=window, **scales),
+        np.float32)
+    live = lengths > 0
+    tol = 5e-5 if q.dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(out[live], expect[live], rtol=tol, atol=tol)
+    assert np.all(out[~live] == 0.0)
+
+
 def _pallas_names(jaxpr) -> list[str]:
     """The ``name`` of every ``pallas_call`` in ``jaxpr``, nested ones too."""
     out = []
