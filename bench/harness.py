@@ -1,9 +1,10 @@
 """Runs one cell of ``BENCHMARK.json`` once and reduces it to a result line.
 
-A run: find the cell's configuration, traffic mix, limits and per-layer
-readers by name; set up the program (weights from the seed, warm-up of
-every shape the traffic uses); measure for ``--seconds``; free the
-program; check its outputs against the plain reference; print.
+A run: find the cell's configuration, its model family, traffic mix,
+limits and per-layer readers by name; set up the program (weights from the
+seed, warm-up of every shape the traffic uses); measure for
+``--seconds``; free the program; check its outputs against the family's
+plain reference; print.
 
 The kind of traffic (``"kind"`` in the traffic file) names the driver in
 ``bench/kinds/<kind>.py``, which owns set-up, the window, the end-to-end
@@ -45,6 +46,7 @@ class Cell:
     limits: dict
     end_to_end: list[dict]
     per_layer: list[dict]
+    family: object                   # bench/families/<model_type>.py
 
 
 def load_cell(name: str, root: Path = ROOT) -> Cell:
@@ -60,22 +62,42 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if name in m.get("workloads", [name] if m["moves"] in moved
                                   else [])]
-    return Cell(name=name, chips=w["chips"],
-                conf=_json(root / conf_entry["file"]),
+    conf = _json(root / conf_entry["file"])
+    return Cell(name=name, chips=w["chips"], conf=conf,
                 traffic=_json(root / "bench" / "traffic"
                               / f"{w['traffic']}.json"),
                 limits=_json(root / "bench" / "limits" / f"{name}.json"),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer,
+                family=load_family(conf["model_type"], root))
+
+
+def _module(path: Path, prefix: str):
+    name = prefix + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(name: str, root: Path = ROOT) -> Callable:
     """``read(run)`` of ``bench/metrics/<name>.py``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(root / "bench" / "metrics" / f"{name}.py",
+                   "bench_metric_").read
+
+
+def load_family(model_type: str, root: Path = ROOT):
+    """``bench/families/<model_type>.py``, the model family of every
+    configuration whose published ``model_type`` this is: its
+    ``program_config(conf)``, ``shapes(conf)`` and ``NORM_GAINS`` of the
+    weight tree, its plain ``Reference``, its work counts
+    (``matmul_params``, ``attention_flops_per_key`` and the least work of
+    its decode-attention kernel) and ``TINY``, the sizes of its cells on
+    the CPU in a test."""
+    path = root / "bench" / "families" / f"{model_type}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in path.parent.glob("*.py"))
+        raise KeyError(f"no family {model_type!r}; known: {known}")
+    return _module(path, "bench_family_")
 
 
 def load_driver(kind: str):

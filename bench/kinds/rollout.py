@@ -10,8 +10,8 @@ Every scheduler tick is timed on the host; the engine's counters give
 the tokens each tick delivered.
 
 Tokens are sampled at the traffic's temperature; each served token's
-reported log-probability is checked against the float32 reference's
-(``bench/check.py``).
+reported log-probability is checked against the float32 reference of the
+cell's model family (``bench/check.py``).
 """
 from __future__ import annotations
 
@@ -36,6 +36,7 @@ class Driver:
     def __init__(self, ctx):
         self.ctx = ctx
         self.conf, self.t = ctx.conf, ctx.traffic
+        self.family = ctx.cell.family
         self.seed = ctx.seed
 
     # ---- set-up -----------------------------------------------------------
@@ -44,8 +45,8 @@ class Driver:
         from repro.serve import Request, RolloutSpec
 
         t = self.t
-        self.model = bm.build(self.conf)
-        params = weights.make(self.conf, self.seed,
+        self.model = bm.build(self.family, self.conf)
+        params = weights.make(self.family, self.conf, self.seed,
                               device=self.ctx.devices[0])
         _check_tree(params, self.model)
         spec = RolloutSpec(num_slots=t["slots"], block_size=1,
@@ -165,9 +166,9 @@ class Driver:
         return picked
 
     def check(self, limits: dict) -> list:
-        from bench.reference.internlm2 import Reference
-        ref = Reference(self.conf)
-        w = weights.make(self.conf, self.seed, device=self.ctx.devices[0])
+        ref = self.family.Reference(self.conf)
+        w = weights.make(self.family, self.conf, self.seed,
+                         device=self.ctx.devices[0])
         L = self.t["max_seq_len"]
         got = [check.served(ref, w, o.prompt, o.tokens, o.logprobs, L)
                for o in self.sample()]
@@ -183,9 +184,10 @@ class Driver:
         reference in the program's place (its log-probability of each
         served token), and each served token altered where it is produced
         (moved to the next id, its reported log-probability kept)."""
-        from bench.reference.internlm2 import Reference
+        Reference = self.family.Reference
         ref, low = Reference(self.conf), Reference(self.conf, quant="fp8")
-        w = weights.make(self.conf, self.seed, device=self.ctx.devices[0])
+        w = weights.make(self.family, self.conf, self.seed,
+                         device=self.ctx.devices[0])
         L, V = self.t["max_seq_len"], self.conf["vocab_size"]
         picked = self.sample()
         ctl = [check.control(ref, low, w, o.prompt, o.tokens, L)

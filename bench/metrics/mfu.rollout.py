@@ -5,9 +5,9 @@ from bench import counts
 
 
 def read(run):
-    rec, conf = run.record, run.conf
-    flops = sum(counts.forward_flops(conf, t["live"], t["keys"])
+    rec, conf, fam = run.record, run.conf, run.cell.family
+    flops = sum(counts.forward_flops(fam, conf, t["live"], t["keys"])
                 for t in rec["ticks"])
-    flops += sum(counts.forward_flops(conf, p, counts.prefill_keys(p))
+    flops += sum(counts.forward_flops(fam, conf, p, counts.prefill_keys(p))
                  for p in rec["prefill_lengths"])
     return 100.0 * flops / (rec["window_s"] * run.peaks.flops_bf16)

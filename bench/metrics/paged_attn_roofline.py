@@ -1,11 +1,11 @@
 """Share of its roofline that ``paged_decode_attention`` reaches in the
 traced ticks: the least time the chip's peaks allow for the work the
-live rows need (``bench.counts.paged_attention_work``: live KV blocks,
+live rows need (the family's ``paged_attention_work``: live KV blocks,
 q and out; q.k and p.v FLOPs), over the kernel's device time in the
 trace.  The work is memory-bound at every length this traffic sends."""
 from bench import counts, trace
 
-KERNEL = "paged_dec"      # the Pallas kernel's function: _paged_dec_kernel
+KERNEL = "paged_decode_attention"      # the Pallas kernel's name
 
 
 def read(run):
@@ -19,7 +19,7 @@ def read(run):
                          f"traced ticks of {layers} layers")
     t_min = 0.0
     for t in traced:
-        f, b = counts.paged_attention_work(
+        f, b = run.cell.family.paged_attention_work(
             run.conf, t["lengths"], block_size=run.traffic["kv_block_size"])
         t_min += layers * counts.roofline_seconds(f, b, run.peaks)[0]
     busy = sum(e.end_ns - e.start_ns for e in evs) / 1e9

@@ -2,10 +2,11 @@
 gaps named by what the host was doing.
 
 The loader reads the ``.xplane.pb`` that ``jax.profiler`` writes into
-a small schema: per chip, the operations of its ``XLA Ops`` line; for
-the host, the benchmark's own spans (``jax.profiler.TraceAnnotation``
-names starting with ``bench.``).  Everything after the loader works on
-that schema alone, so the tests feed it synthetic traces.
+a small schema: per chip, the operations of its ``XLA Ops`` line, by
+their own names; for the host, the benchmark's own spans
+(``jax.profiler.TraceAnnotation`` names starting with ``bench.``).
+Everything after the loader works on that schema alone, so the tests
+feed it synthetic traces.
 
 The traced window is the host span :data:`WINDOW_SPAN`, which the harness
 opens around the traced part of a run.
@@ -29,7 +30,7 @@ class Event:
     name: str
     start_ns: float
     end_ns: float
-    detail: str = ""          # the op's string stats (kernel names live here)
+    detail: str = ""          # a program span's stats (program_spans)
 
 
 @dataclass
@@ -56,9 +57,7 @@ def load(trace_dir: str) -> Trace:
             if plane.name.startswith(DEVICE_PLANE) and line.name == OPS_LINE:
                 chip = int(plane.name[len(DEVICE_PLANE):].split()[0])
                 tr.ops[chip] = [
-                    Event(e.name, e.start_ns, e.start_ns + e.duration_ns,
-                          " ".join(str(v) for _, v in e.stats
-                                   if isinstance(v, str)))
+                    Event(e.name, e.start_ns, e.start_ns + e.duration_ns)
                     for e in line.events]
             elif not plane.name.startswith("/device:"):
                 tr.spans += [Event(e.name, e.start_ns,
@@ -112,12 +111,14 @@ def window_seconds(tr: Trace) -> float:
 
 
 def kernel_events(tr: Trace, pattern: str) -> list[Event]:
-    """Operations inside the window whose name or string stats contain
-    ``pattern``, over all chips."""
+    """Operations inside the window whose own name (:func:`op_name`)
+    contains ``pattern``, over all chips.  An op's text and stats name its
+    operands too, so an op that takes a kernel's output is not that
+    kernel."""
     lo, hi = window(tr)
     return [e for c in tr.ops for e in tr.ops[c]
             if e.start_ns >= lo and e.end_ns <= hi
-            and (pattern in e.name or pattern in e.detail)]
+            and pattern in op_name(e.name)]
 
 
 def idle_gaps(tr: Trace, chip: int) -> list[tuple[str, float]]:

@@ -1,9 +1,7 @@
 """Tiny sizes at which the benchmark's cells run on the CPU in a test:
-the program's reduced widths (2 layers, d_model 256, vocab 512) and a
-traffic mix cut to a few short requests."""
-
-TINY = dict(hidden_size=256, num_hidden_layers=2, num_attention_heads=4,
-            num_key_value_heads=2, intermediate_size=512, vocab_size=512)
+each configuration's family's ``TINY`` widths and a traffic mix cut to a
+few short requests."""
+from bench import harness
 
 # Limits of the output check at these sizes in bfloat16, set the way the
 # cells' own are, from readings on the CPU over seeds 1-6 (the program's
@@ -14,15 +12,18 @@ LIMITS = {
 }
 
 
-def tiny_conf(conf: dict, dtype: str) -> dict:
-    return dict(conf, **TINY, torch_dtype=dtype)
+def tiny_conf(conf: dict, dtype: str, family=None) -> dict:
+    """``conf`` at its family's ``TINY`` sizes in ``dtype``; the family is
+    found by the file's ``model_type`` unless given."""
+    family = family or harness.load_family(conf["model_type"])
+    return dict(conf, **family.TINY, torch_dtype=dtype)
 
 
 def shrink_for(dtype: str, limits: dict | None = None):
     """A ``run_cell`` seam: cut the loaded cell to tiny sizes in ``dtype``
     and, when given, hold it to ``limits``."""
     def shrink(cell):
-        cell.conf = tiny_conf(cell.conf, dtype)
+        cell.conf = tiny_conf(cell.conf, dtype, cell.family)
         t = dict(cell.traffic)
         t.update(prompts=2, group=2, prompt_lengths=[16, 32], slots=4,
                  num_kv_blocks=16, max_seq_len=48, check_requests=2)

@@ -1,13 +1,15 @@
 """Shape-based counts, the table of peaks, and the shares built on them."""
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import pytest
 
-from bench import counts, device
+from bench import counts, device, harness
 
 ROOT = Path(__file__).resolve().parents[2]
 CONF = json.loads((ROOT / "bench/configs/internlm2-1.8b.json").read_text())
+FAMILY = harness.load_family(CONF["model_type"])
 V5E = device.PEAKS["TPU v5 lite"]
 
 
@@ -16,20 +18,20 @@ def test_matmul_params_by_hand():
     # three 2048 x 8192 MLP matrices; plus the 2048 x 92,544 head
     layer = 2 * 2048 * 2048 + 2 * 2048 * 1024 + 3 * 2048 * 8192
     assert layer == 62_914_560
-    assert counts.matmul_params(CONF) == 24 * layer + 2048 * 92_544
+    assert FAMILY.matmul_params(CONF) == 24 * layer + 2048 * 92_544
 
 
 def test_forward_flops_by_hand():
-    n = counts.matmul_params(CONF)
+    n = FAMILY.matmul_params(CONF)
     # 10 tokens reading 55 keys in all (a causal prefill of 10)
     assert counts.prefill_keys(10) == 55
-    assert counts.forward_flops(CONF, 10, 55) == \
+    assert counts.forward_flops(FAMILY, CONF, 10, 55) == \
         2 * n * 10 + 4 * 24 * 16 * 128 * 55
 
 
 def test_paged_attention_work_by_hand():
     # rows of 17 and 16 live positions in blocks of 16: 2 + 1 blocks
-    f, b = counts.paged_attention_work(CONF, [17, 16], block_size=16)
+    f, b = FAMILY.paged_attention_work(CONF, [17, 16], block_size=16)
     assert f == 4 * 16 * 128 * (17 + 16)
     kv = 2 * (2 + 1) * 16 * 8 * 128 * 2          # K and V, bf16
     qo = 2 * 2 * 16 * 128 * 2                    # q and out of both rows
@@ -50,7 +52,7 @@ def test_roofline_share_never_exceeds_one_on_consistent_inputs(slack):
     from bench.harness import Run, load_reader
     from bench.trace import WINDOW_SPAN, Event, Trace
     lengths = [[130, 17, 1024], [131, 18]]               # two traced ticks
-    t_call = [counts.roofline_seconds(*counts.paged_attention_work(
+    t_call = [counts.roofline_seconds(*FAMILY.paged_attention_work(
         CONF, ls, block_size=16), V5E)[0] for ls in lengths]
     ops, t = [], 0.0
     for ti, ls in enumerate(lengths):
@@ -60,7 +62,8 @@ def test_roofline_share_never_exceeds_one_on_consistent_inputs(slack):
             t += d
     tr = Trace(ops={0: ops}, spans=[Event(WINDOW_SPAN, 0, t)])
     ticks = [{"traced": True, "lengths": ls} for ls in lengths]
-    run = Run(cell=None, conf=CONF, traffic={"kv_block_size": 16},
+    run = Run(cell=SimpleNamespace(family=FAMILY), conf=CONF,
+              traffic={"kv_block_size": 16},
               peaks=V5E, record={"ticks": ticks}, trace=tr)
     share = load_reader("paged_attn_roofline")(run)
     assert share == pytest.approx(100.0 / slack)

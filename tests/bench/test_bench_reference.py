@@ -10,14 +10,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import check, weights
+from bench import check, harness, weights
 from bench import model as bm
-from bench.reference.internlm2 import Reference
 from bench_tiny import tiny_conf
 
 ROOT = Path(__file__).resolve().parents[2]
 CONF = tiny_conf(json.loads(
     (ROOT / "bench/configs/internlm2-1.8b.json").read_text()), "float32")
+FAMILY = harness.load_family(CONF["model_type"])
+Reference = FAMILY.Reference
 # float32 program against the float32 reference: both sum in another
 # order, so agreement is to float32 rounding over 2 layers, far inside
 # 1e-4; one bfloat16 rounding of the operands moves logits by about 1e-3
@@ -30,8 +31,8 @@ def served():
     """Sampled requests through the paged engine with the Pallas decode
     kernels (interpreted), two of them sharing a prompt prefix."""
     from repro.serve import Request, RolloutSpec
-    model = bm.build(CONF)
-    w = weights.make(CONF, 5)
+    model = bm.build(FAMILY, CONF)
+    w = weights.make(FAMILY, CONF, 5)
     eng = RolloutSpec(num_slots=3, kv_layout="paged", kv_block_size=4,
                       prefix_share=True, kernel_backend="pallas").build_engine(
         model, w, batch=3, max_seq_len=40, eos_id=-1, temperature=1.0,
@@ -99,8 +100,8 @@ def _worst_leaf_gap(grads, r_grads) -> float:
 @pytest.mark.parametrize("quant,agree", [(None, True), ("bf16", False)])
 def test_grpo_loss_and_gradient_match_reference(quant, agree):
     from repro.rl.train_step import make_loss_fn
-    model = bm.build(CONF)
-    w = weights.make(CONF, 7)
+    model = bm.build(FAMILY, CONF)
+    w = weights.make(FAMILY, CONF, 7)
     batch = _grpo_batch()
     (loss, _), grads = jax.value_and_grad(
         make_loss_fn(model, remat=False), has_aux=True)(w, batch)

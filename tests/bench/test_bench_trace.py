@@ -45,6 +45,17 @@ def test_kernel_time_by_name():
     assert sum(e.end_ns - e.start_ns for e in evs) / 1e9 == pytest.approx(0.040)
 
 
+def test_op_naming_the_kernels_output_is_not_counted():
+    """An op that takes a kernel's output names it in its text and in its
+    stats, as its operand; it is another op, not a call of the kernel."""
+    tr = synthetic()
+    tr.ops[0].append(Event(
+        "%bitcast_fusion.4 = bf16[64,16,128] fusion(%paged_decode_attention.7)",
+        50 * MS, 55 * MS, "%paged_decode_attention.7 = bf16[64,8,2,128]"))
+    evs = trace.kernel_events(tr, "paged_dec")
+    assert [e.start_ns for e in evs] == [10 * MS, 30 * MS]
+
+
 def test_idle_gaps_named_by_innermost_host_span():
     gaps = trace.idle_gaps(synthetic(), 0)
     # 0-10 ms: midpoint 5 ms lies in step and (at its edge) submit, whose
